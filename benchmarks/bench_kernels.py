@@ -44,10 +44,8 @@ def run(out_dir: str = "benchmarks/results") -> list:
 
         # unfused reference: knm written to HBM then re-read for projection
         ref_fn = jax.jit(lambda *a: ops.svgp_projection_ref(*a))
-        from repro.runtime import compat
-
         c = ref_fn.lower(x, z, lls, lv, lmm).compile()
-        ca = compat.cost_analysis(c)
+        ca = c.cost_analysis()
         # fused kernel skips one HBM write+read of knm (B x m fp32)
         knm_bytes = B * m * 4
         t0 = time.time()

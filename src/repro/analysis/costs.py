@@ -69,9 +69,7 @@ METRICS = ("flops", "bytes_accessed", "arg_bytes", "out_bytes", "temp_bytes")
 
 def extract(compiled) -> dict:
     """Flatten one compiled program's cost + memory stats to a JSON row."""
-    from repro.runtime import compat
-
-    ca = compat.cost_analysis(compiled)
+    ca = compiled.cost_analysis()
     mem = compiled.memory_analysis()
     return {
         "flops": float(ca.get("flops", 0.0)),

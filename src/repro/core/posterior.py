@@ -117,12 +117,13 @@ def build_cache(
     if whitened:
         # u = L v, q(v)=N(m_star, S): fmean = k^T Lmm^{-T} m_star
         c = jsl.solve_triangular(lmm.T, params.m_star, lower=False)
-        u = sl.T @ w
+        u = jnp.dot(sl.T, w, precision="highest")
     else:
         c = jsl.solve_triangular(
             lmm.T, jsl.solve_triangular(lmm, params.m_star, lower=True), lower=False
         )
-        u = sl.T @ (w.T @ w)  # Sl^T Kmm^{-1}
+        kinv = jnp.dot(w.T, w, precision="highest")  # Kmm^{-1}, full f32
+        u = jnp.dot(sl.T, kinv, precision="highest")
     return PosteriorCache(
         z=params.z, w=w, u=u, c=c, cov=params.cov, log_beta=params.log_beta
     )
@@ -155,9 +156,11 @@ def predict_cached(
         )
     else:
         knm = cov_fn(cache.cov, xstar, cache.z)  # (Q, m)
-        fmean = knm @ cache.c
-        qd = jnp.sum((knm @ cache.w.T) ** 2, axis=-1)
-        sd = jnp.sum((knm @ cache.u.T) ** 2, axis=-1)
+        # full-f32 matmuls: the variance terms cancel, and at a TPU's
+        # default f32 precision the variance comes out ~10% off (PERF.md)
+        fmean = jnp.dot(knm, cache.c, precision="highest")
+        qd = jnp.sum(jnp.dot(knm, cache.w.T, precision="highest") ** 2, axis=-1)
+        sd = jnp.sum(jnp.dot(knm, cache.u.T, precision="highest") ** 2, axis=-1)
         fvar = kdiag(cache.cov, xstar) - qd + sd
     fvar = jnp.maximum(fvar, 1e-12)
     if include_noise:

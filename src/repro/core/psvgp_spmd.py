@@ -32,7 +32,6 @@ from repro.core.partition import PartitionGrid
 from repro.core.psvgp import PSVGPConfig, PSVGPState, _loss_one
 from repro.core.sampler import sample_row_indices
 from repro.optim import adam_update
-from repro.runtime import compat
 
 
 def _row_axes(axes: Sequence[str]) -> tuple[str, ...]:
@@ -161,7 +160,7 @@ def make_spmd_step(
         step=P(),
     )
 
-    step_fn = compat.shard_map(
+    step_fn = jax.shard_map(
         step_shard,
         mesh=mesh,
         in_specs=(state_specs, P(), pspec, pspec, pspec, pspec, pspec),

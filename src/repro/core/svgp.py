@@ -124,15 +124,16 @@ def q_f(
     """
     lk, kd, lmm = _projection(params, cov_fn, x, jitter, use_pallas)
     sl = s_chol(params.s_tril)  # (m, m)
+    # full-f32 matmuls, like the served variance (posterior.predict_cached)
     if whitened:
         # u = L v, q(v)=N(m_star, S): fmean = lk^T m_star, a_i^T S a_i = ||sl^T lk||^2
-        fmean = lk.T @ params.m_star
-        tmp = sl.T @ lk  # (m, B)
+        fmean = jnp.dot(lk.T, params.m_star, precision="highest")
+        tmp = jnp.dot(sl.T, lk, precision="highest")  # (m, B)
         fvar = kd + jnp.sum(tmp * tmp, axis=0)
     else:
         a = jsl.solve_triangular(lmm.T, lk, lower=False)  # (m, B) = Kmm^{-1} k_i
-        fmean = a.T @ params.m_star
-        tmp = sl.T @ a
+        fmean = jnp.dot(a.T, params.m_star, precision="highest")
+        tmp = jnp.dot(sl.T, a, precision="highest")
         fvar = kd + jnp.sum(tmp * tmp, axis=0)
     return fmean, jnp.maximum(fvar, 1e-12)
 

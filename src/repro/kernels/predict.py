@@ -59,15 +59,18 @@ def _predict_kernel_body(
     knm = var * jnp.exp(-0.5 * r2)
     # VPU: mean = knm @ c with c resident as a (1, m) row.
     mean_ref[...] = jnp.sum(knm * c_ref[...], axis=-1, keepdims=True)
-    # MXU: two (bq, m) @ (m, m) projections, fp32 accumulation.
+    # MXU: two (bq, m) @ (m, m) projections at full f32 precision — the
+    # variance below cancels, so bf16 passes would swamp it.
     lk = jax.lax.dot_general(
         knm, w_ref[...],
         dimension_numbers=(((1,), (1,)), ((), ())),  # knm @ W^T
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32,
     ).astype(knm.dtype)
     su = jax.lax.dot_general(
         knm, u_ref[...],
         dimension_numbers=(((1,), (1,)), ((), ())),  # knm @ U^T
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32,
     ).astype(knm.dtype)
     fvar_ref[...] = (
@@ -142,11 +145,13 @@ def _predict_slots_kernel_body(
     lk = jax.lax.dot_general(
         knm, w_ref[...],
         dimension_numbers=(((1,), (1,)), ((), ())),  # knm @ W^T
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32,
     ).astype(knm.dtype)
     su = jax.lax.dot_general(
         knm, u_ref[...],
         dimension_numbers=(((1,), (1,)), ((), ())),  # knm @ U^T
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32,
     ).astype(knm.dtype)
     fvar_ref[0] = (

@@ -41,7 +41,7 @@ def svgp_projection(
       q_diag (B,)    ||Lmm^{-1} k_i||^2 = k_i^T Kmm^{-1} k_i
     """
     knm = rbf_cross_cov(x, z, log_lengthscale, log_variance)
-    lk_t = knm @ w.T
+    lk_t = jnp.dot(knm, w.T, precision="highest")
     q_diag = jnp.sum(lk_t * lk_t, axis=-1)
     return knm, lk_t, q_diag
 
@@ -63,9 +63,11 @@ def posterior_predict(
       fvar (Q,)  k_** - ||W k_*||^2 + ||U k_*||^2   (un-clamped)
     """
     knm = rbf_cross_cov(x, z, log_lengthscale, log_variance)
-    mean = knm @ c
-    lk = knm @ w.T
-    su = knm @ u.T
+    # full-f32 matmuls like the kernels: an oracle at a TPU's default
+    # precision would sit further from the truth than the code it checks
+    mean = jnp.dot(knm, c, precision="highest")
+    lk = jnp.dot(knm, w.T, precision="highest")
+    su = jnp.dot(knm, u.T, precision="highest")
     fvar = jnp.exp(log_variance) - jnp.sum(lk * lk, axis=-1) + jnp.sum(su * su, axis=-1)
     return mean, fvar
 

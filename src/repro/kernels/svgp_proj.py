@@ -32,11 +32,13 @@ def _proj_kernel_body(x_ref, z_ref, invl_ref, var_ref, w_ref, knm_ref, lkt_ref, 
     r2 = jnp.sum(diff * diff, axis=-1)  # (bb, m)
     knm = var_ref[0, 0] * jnp.exp(-0.5 * r2)
     knm_ref[...] = knm
-    # MXU: (bb, m) @ (m, m). fp32 accumulation regardless of input dtype.
+    # MXU: (bb, m) @ (m, m) at full f32 precision: q_diag feeds the
+    # cancelling k~ = k - q_diag of the ELBO.
     lkt = jax.lax.dot_general(
         knm,
         w_ref[...],
         dimension_numbers=(((1,), (1,)), ((), ())),  # knm @ W^T
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32,
     ).astype(knm.dtype)
     lkt_ref[...] = lkt

@@ -33,7 +33,6 @@ from jax.sharding import PartitionSpec as P
 from repro.configs import ARCH_IDS, INPUT_SHAPES, get, input_specs, swa_variant
 from repro.launch import hlo_analysis
 from repro.launch.mesh import make_production_mesh
-from repro.runtime import compat
 from repro.models import transformer
 from repro.runtime.steps import (
     init_train_state,
@@ -67,15 +66,15 @@ def _lower_combo(cfg, shape_name: str, mesh, fsdp: bool = False, microbatches: i
     pspecs = state_pspecs(state_shapes, mesh, fsdp=fsdp)
     bspec = batch_pspec(mesh) if _data_shardable(sh.global_batch, mesh) else P()
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if sh.kind == "train":
             specs = input_specs(cfg, shape_name)
             batch_specs = {k: bspec if v.ndim >= 2 else P() for k, v in specs.items()}
             step = make_train_step(cfg, microbatches=microbatches)
             jitted = jax.jit(
                 step,
-                in_shardings=compat.named_shardings(mesh, (pspecs, batch_specs)),
-                out_shardings=compat.named_shardings(mesh, (pspecs, None)),
+                in_shardings=(pspecs, batch_specs),
+                out_shardings=(pspecs, None),
             )
             lowered = jitted.lower(state_shapes, specs)
         elif sh.kind == "prefill":
@@ -85,7 +84,7 @@ def _lower_combo(cfg, shape_name: str, mesh, fsdp: bool = False, microbatches: i
             in_sh = [pspecs.params] + [bspec for _ in names]
             jitted = jax.jit(
                 lambda params, *args: step(params, **dict(zip(names, args, strict=True))),
-                in_shardings=compat.named_shardings(mesh, tuple(in_sh)),
+                in_shardings=tuple(in_sh),
             )
             lowered = jitted.lower(state_shapes.params, *[specs[k] for k in names])
         else:  # decode
@@ -102,8 +101,8 @@ def _lower_combo(cfg, shape_name: str, mesh, fsdp: bool = False, microbatches: i
             pos_spec = jax.ShapeDtypeStruct((), jnp.int32)
             jitted = jax.jit(
                 step,
-                in_shardings=compat.named_shardings(mesh, (pspecs.params, cspecs, P(), bspec)),
-                out_shardings=compat.named_shardings(mesh, (None, cspecs)),
+                in_shardings=(pspecs.params, cspecs, P(), bspec),
+                out_shardings=(None, cspecs),
             )
             lowered = jitted.lower(state_shapes.params, cache_shapes, pos_spec, tok_spec)
 
@@ -306,7 +305,7 @@ def dryrun_psvgp(*, multi_pod: bool = False, comm: str = "ppermute", verbose: bo
     p_dir = jnp.full((5,), 0.2, f32)
 
     cov_fn = make_covariance(cfg.svgp.covariance)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if comm == "ppermute":
             step = make_spmd_step(mesh, axes, grid, cfg, cov_fn, p_dir)
             lowered = step.lower(
@@ -328,10 +327,8 @@ def dryrun_psvgp(*, multi_pod: bool = False, comm: str = "ppermute", verbose: bo
                 functools.partial(
                     psvgp.train_step_gather, cfg=cfg, cov_fn=cov_fn
                 ),
-                in_shardings=compat.named_shardings(
-                    mesh, (sspec, P(), pspec, pspec, pspec, dspec)
-                ),
-                out_shardings=compat.named_shardings(mesh, (sspec, None)),
+                in_shardings=(sspec, P(), pspec, pspec, pspec, dspec),
+                out_shardings=(sspec, None),
             )
             lowered = jitted.lower(
                 state, sds((2,), jnp.uint32),

@@ -79,9 +79,7 @@ class RooflineTerms(NamedTuple):
 
 
 def roofline(compiled) -> RooflineTerms:
-    from repro.runtime import compat
-
-    ca = compat.cost_analysis(compiled)
+    ca = compiled.cost_analysis()
     flops = float(ca.get("flops", 0.0))
     byts = float(ca.get("bytes accessed", 0.0))
     breakdown = collective_bytes(compiled.as_text())

@@ -7,19 +7,17 @@ never touches jax device state — the dry-run must set
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
-
-from repro.runtime import compat
+from jax.sharding import AxisType, Mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     """16x16 = 256 chips per pod (TPU v5e pod slice); 2 pods = 512 chips."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh() -> Mesh:
     """Whatever this host actually has (CPU: 1 device) — for examples."""
     n = jax.device_count()
-    return compat.make_mesh((n, 1), ("data", "model"))
+    return jax.make_mesh((n, 1), ("data", "model"), axis_types=(AxisType.Auto,) * 2)

@@ -94,10 +94,12 @@ def main() -> None:
     # the --gp-* flags are owned by serve_sharded (one definition for both
     # entry points); its import is device-state free, so the virtual-device
     # setup of --sharded still works.
+    from repro.launch import use_compile_cache
     from repro.launch.serve_sharded import add_gp_args
 
     add_gp_args(ap)
     args = ap.parse_args()
+    use_compile_cache()
 
     if args.sharded and not args.gp:
         ap.error("--sharded only applies to the GP serving mode (add --gp)")

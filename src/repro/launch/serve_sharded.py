@@ -75,48 +75,73 @@ from collections.abc import Callable, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from repro.analysis.contracts import contract
 from repro.core import posterior, routing
 from repro.core.partition import PartitionGrid
 from repro.core.psvgp_spmd import grid_matches_mesh, shift_perm
-from repro.runtime import compat
+from repro.launch import use_compile_cache
 from repro.sharding import gp_stacked_pspecs
 
 
-def ensure_host_devices(n: int) -> None:
-    """Force >= n virtual host devices (must run before jax backend init).
+def _cpu_platform_in_use() -> bool:
+    """Whether this process's jax runs on the CPU backend — decided
+    WITHOUT initializing a backend when none is up yet (initializing would
+    bind the host device count before the caller could force it)."""
+    from jax._src import hardware_utils, xla_bridge
 
-    The host-device-count flag is written into XLA_FLAGS unconditionally
-    (we cannot count devices without initializing the backend, and after
-    init it is too late to set it) — on a real TPU slice the flag is inert
-    for this process but IS inherited by child processes that run
-    CPU-backed jax. An already-present but too-small count is rewritten
-    upward (it only binds at backend init, so rewriting is still effective
-    here). Raises with guidance if the backend initialized too early for
-    the flag to take effect.
+    if xla_bridge.backends_are_initialized():
+        return jax.default_backend() == "cpu"
+    if jax.config.jax_platforms:
+        return jax.config.jax_platforms.split(",")[0] == "cpu"
+    # no platform named: jax picks an attached accelerator when there is one
+    return (
+        hardware_utils.num_available_tpu_chips_and_device_id()[0] == 0
+        and not hardware_utils.has_visible_nvidia_gpu()
+    )
+
+
+def ensure_host_devices(n: int) -> None:
+    """Make sure one-partition-per-device serving has >= n devices.
+
+    On the CPU platform the devices are virtual: the host-device-count
+    flag is written into XLA_FLAGS (an already-present but too-small
+    count is rewritten upward), which binds only if the backend has not
+    initialized yet. On an accelerator XLA_FLAGS is left alone — the
+    devices are the real chips. Raises when fewer than n devices exist,
+    with the CPU flag advice only where the flag applies.
     """
     import re
 
-    flags = os.environ.get("XLA_FLAGS", "")
-    flag_re = r"--xla_force_host_platform_device_count=(\d+)"
-    m = re.search(flag_re, flags)
-    if m is None:
-        os.environ["XLA_FLAGS"] = (
-            flags + f" --xla_force_host_platform_device_count={n}"
-        ).strip()
-    elif int(m.group(1)) < n:
-        os.environ["XLA_FLAGS"] = re.sub(
-            flag_re, f"--xla_force_host_platform_device_count={n}", flags
-        )
-    if jax.device_count() < n:
+    cpu = _cpu_platform_in_use()
+    if cpu:
+        flags = os.environ.get("XLA_FLAGS", "")
+        flag_re = r"--xla_force_host_platform_device_count=(\d+)"
+        m = re.search(flag_re, flags)
+        if m is None:
+            os.environ["XLA_FLAGS"] = (
+                flags + f" --xla_force_host_platform_device_count={n}"
+            ).strip()
+        elif int(m.group(1)) < n:
+            os.environ["XLA_FLAGS"] = re.sub(
+                flag_re, f"--xla_force_host_platform_device_count={n}", flags
+            )
+    have = jax.device_count()
+    if have >= n:
+        return
+    if cpu:
         raise RuntimeError(
             f"need {n} devices for one-partition-per-device serving, have "
-            f"{jax.device_count()}. Set XLA_FLAGS="
+            f"{have}. Set XLA_FLAGS="
             f"--xla_force_host_platform_device_count={n} before jax "
             "initializes (import order matters), or shrink --gp-grid."
         )
+    raise RuntimeError(
+        f"need {n} devices for one-partition-per-device serving, found "
+        f"{have} {jax.default_backend()} device(s) — serve a grid of at "
+        f"most {have} partitions here, or run on more chips."
+    )
 
 
 def mesh_for_grid(grid: PartitionGrid) -> Mesh:
@@ -124,7 +149,9 @@ def mesh_for_grid(grid: PartitionGrid) -> Mesh:
     — the serving analogue of the training mapping in
     ``repro.core.psvgp_spmd`` (grid x-steps shift along ``model``, y-steps
     along ``data``)."""
-    return compat.make_mesh((grid.gy, grid.gx), ("data", "model"))
+    return jax.make_mesh(
+        (grid.gy, grid.gx), ("data", "model"), axis_types=(AxisType.Auto,) * 2
+    )
 
 
 def shard_cache(
@@ -186,7 +213,7 @@ def make_halo_gather(mesh: Mesh, axes: Sequence[str], grid: PartitionGrid):
 
     pspec = P(tuple(axes))
     return jax.jit(
-        compat.shard_map(
+        jax.shard_map(
             gather, mesh=mesh, in_specs=(pspec,), out_specs=pspec, check_vma=False
         )
     )
@@ -296,7 +323,7 @@ def make_sharded_blend(
         return bmean[None], bvar[None]
 
     pspec = P(tuple(axes))
-    step_fn = compat.shard_map(
+    step_fn = jax.shard_map(
         step,
         mesh=mesh,
         in_specs=(cache_in_specs(cache_like, pspec), pspec, pspec, pspec),
@@ -844,6 +871,7 @@ def main() -> None:
     args = ap.parse_args()
     if args.gp_requests < 1 or args.gp_batch < 1:
         ap.error("--gp-requests and --gp-batch must be >= 1")
+    use_compile_cache()
     if args.http:
         # imports and argparse above never initialize the jax backend, so
         # the HTTP driver can still force the virtual device count.

@@ -19,8 +19,8 @@ import numpy as np
 from repro.checkpoint import load_train_state, save_train_state
 from repro.configs import get, get_smoke
 from repro.data.tokens import synthetic_token_batches
+from repro.launch import use_compile_cache
 from repro.launch.mesh import make_host_mesh
-from repro.runtime import compat
 from repro.runtime.steps import init_train_state, make_train_step
 from repro.sharding import state_pspecs
 
@@ -38,6 +38,7 @@ def main() -> None:
     ap.add_argument("--ckpt-every", type=int, default=0)
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_smoke(args.arch) if args.smoke else get(args.arch)
     mesh = make_host_mesh()
@@ -53,11 +54,11 @@ def main() -> None:
     print(f"parameters: {n_params/1e6:.2f}M")
 
     pspecs = state_pspecs(state, mesh)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         step_fn = jax.jit(
             make_train_step(cfg, learning_rate=args.lr),
-            in_shardings=compat.named_shardings(mesh, (pspecs, None)),
-            out_shardings=compat.named_shardings(mesh, (pspecs, None)),
+            in_shardings=(pspecs, None),
+            out_shardings=(pspecs, None),
         )
         data = synthetic_token_batches(cfg.vocab_size, args.batch, args.seq, seed=args.seed)
         rng = np.random.default_rng(args.seed)

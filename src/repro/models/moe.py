@@ -18,7 +18,6 @@ import jax.numpy as jnp
 
 from repro.models.common import Params, dense_init
 from repro.models.config import ModelConfig, MoEConfig
-from repro.runtime import compat
 
 
 def init_moe_params(key: jax.Array, cfg: ModelConfig) -> Params:
@@ -50,10 +49,9 @@ def moe_forward(p: Params, cfg: ModelConfig, x: jnp.ndarray) -> tuple[jnp.ndarra
     auto-partitioner replicates the D-wide dispatch scatters otherwise
     (measured: ~5 GiB all-gathers per layer, EXPERIMENTS.md §Perf-2).
     """
-    mesh = compat.get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     if (
-        mesh is not None
-        and "model" in mesh.axis_names
+        "model" in mesh.axis_names
         and mesh.shape["model"] > 1
         and cfg.moe.num_experts % mesh.shape["model"] == 0
     ):
@@ -214,7 +212,7 @@ def _moe_forward_spmd(p: Params, cfg: ModelConfig, x: jnp.ndarray, mesh) -> tupl
         out_l = jax.lax.psum(out_l, "model")
         return out_l.reshape(Bl, S, D), aux_l
 
-    out, aux = compat.shard_map(
+    out, aux = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(x_spec, P(), P("model"), P("model"), P("model")),

@@ -409,6 +409,7 @@ async def _run(server, net_cfg) -> None:
 
 
 def main() -> None:
+    from repro.launch import use_compile_cache
     from repro.launch.serve_sharded import add_gp_args
 
     ap = argparse.ArgumentParser(description=__doc__)
@@ -416,6 +417,7 @@ def main() -> None:
     add_gp_args(ap)
     args = ap.parse_args()
     args.http = True  # this module IS the http entry point
+    use_compile_cache()
     serve_http(args)
 
 

@@ -688,13 +688,12 @@ _INJECT_SCRIPT = textwrap.dedent(
     from repro.analysis import hlo
     from repro.analysis import invariants as inv
     from repro.launch import serve_sharded as ss
-    from repro.runtime import compat
 
     grid = hlo.probe_grid(4)
     mesh = ss.mesh_for_grid(grid)
 
     # an all_gather smuggled into a shard_map program: the factors move
-    gathered = jax.jit(compat.shard_map(
+    gathered = jax.jit(jax.shard_map(
         lambda x: jax.lax.all_gather(x, mesh.axis_names[0]),
         mesh=mesh, in_specs=P(tuple(mesh.axis_names)), out_specs=P(),
         check_vma=False,
@@ -806,7 +805,6 @@ _COST_INJECT_SCRIPT = textwrap.dedent(
     from repro.analysis import costs, hlo
     from repro.analysis import invariants as inv
     from repro.launch import serve_sharded as ss
-    from repro.runtime import compat
 
     def f32(*shape):
         return jax.ShapeDtypeStruct(shape, jnp.float32)
@@ -818,7 +816,7 @@ _COST_INJECT_SCRIPT = textwrap.dedent(
         mesh = ss.mesh_for_grid(grid)
         ax = mesh.axis_names[0]
         Pn = grid.num_partitions
-        fn = jax.jit(compat.shard_map(
+        fn = jax.jit(jax.shard_map(
             lambda cache, q: (q @ cache.T).sum(-1),
             mesh=mesh, in_specs=(P(), P(ax)), out_specs=P(ax),
             check_vma=False,
@@ -849,7 +847,7 @@ _COST_INJECT_SCRIPT = textwrap.dedent(
         mesh = ss.mesh_for_grid(grid)
         ax = mesh.axis_names[0]
         Pn = grid.num_partitions
-        fn = jax.jit(compat.shard_map(
+        fn = jax.jit(jax.shard_map(
             lambda q: ((q[:, :, None, :] - q[:, None, :, :]) ** 2
                        ).sum((-1, -2, -3)),
             mesh=mesh, in_specs=P(ax), out_specs=P(ax), check_vma=False,

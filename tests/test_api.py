@@ -254,7 +254,11 @@ def test_predict_cached_slots_backend_lanes_agree():
             cache, cov_fn, xslots, backend=backend
         )
         np.testing.assert_allclose(np.asarray(m_b), np.asarray(m_ref), atol=1e-5)
-        np.testing.assert_allclose(np.asarray(v_b), np.asarray(v_ref), atol=1e-5)
+        # Relative: this untrained model's Kmm is near-singular (U entries
+        # ~2e3 against variances ~1e3, a ~3e4-fold cancellation inside the
+        # projection), so every f32 lane sits ~1e-4 (relative) from the
+        # float64 answer and two lanes may differ by about twice that.
+        np.testing.assert_allclose(np.asarray(v_b), np.asarray(v_ref), rtol=3e-4)
     with pytest.raises(ValueError, match="not both"):
         posterior.predict_cached_slots(
             cache, cov_fn, xslots, use_pallas=True, backend="ref"

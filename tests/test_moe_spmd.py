@@ -17,10 +17,9 @@ _SCRIPT = textwrap.dedent(
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=16"
     import dataclasses
     import jax, jax.numpy as jnp, numpy as np
-    from jax.sharding import PartitionSpec as P
+    from jax.sharding import AxisType, PartitionSpec as P
     from repro.models.config import ModelConfig, MoEConfig
     from repro.models.moe import init_moe_params, moe_forward, _moe_forward_local
-    from repro.runtime import compat
 
     cfg = ModelConfig(
         name="t", arch_type="moe", num_layers=2, d_model=64, num_heads=4,
@@ -35,8 +34,8 @@ _SCRIPT = textwrap.dedent(
     out_ref, aux_ref = _moe_forward_local(p, cfg, x)
     gref = jax.grad(lambda pp: _moe_forward_local(pp, cfg, x)[0].sum())(p)
 
-    mesh = compat.make_mesh((8, 2), ("data", "model"))
-    with compat.set_mesh(mesh):
+    mesh = jax.make_mesh((8, 2), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
+    with jax.set_mesh(mesh):
         out, aux = jax.jit(lambda pp, xx: moe_forward(pp, cfg, xx))(p, x)
         g = jax.jit(jax.grad(lambda pp: moe_forward(pp, cfg, x)[0].sum()))(p)
 

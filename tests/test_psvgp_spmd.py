@@ -20,7 +20,6 @@ _SCRIPT = textwrap.dedent(
     from repro.core.partition import make_grid, partition_data
     from repro.core import psvgp, svgp
     from repro.core.psvgp_spmd import make_spmd_step
-    from repro.runtime import compat
 
     ds = e3sm_like_field(n=2000, seed=0)
     grid = make_grid(ds.x, gx=4, gy=4)
@@ -40,7 +39,7 @@ _SCRIPT = textwrap.dedent(
     # below Adam's chaotic divergence horizon (the sqrt(nu) normalization
     # amplifies float-reassociation noise exponentially across steps; step-0
     # agreement is ~1e-9, step-4 would be ~1e-3 with identical math).
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         for _ in range(2):
             st_spmd, loss_spmd = step(
                 st_spmd, key, data.x, data.y, data.mask,

@@ -14,6 +14,8 @@ must come from the pytree STRUCTURE of the cache being served, never from
 a hand-built field-by-field literal that a future PosteriorCache field
 would silently desync from.
 """
+import os
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -202,3 +204,46 @@ def test_streaming_policy_drives_pipeline_shapes():
     assert policy.compiles == len(set(q_maxes))  # every shape counted once
     assert policy.compiles <= 4  # 3 growth steps + first on this stream
     assert policy.overflows == policy.compiles - 1
+
+
+@pytest.mark.parametrize("cpu", [True, False], ids=["cpu", "accelerator"])
+def test_ensure_host_devices_flag_only_on_cpu(monkeypatch, cpu):
+    """The virtual-device flag is a CPU-platform device: on an accelerator
+    XLA_FLAGS stays untouched (the mesh is the real chips), and a
+    shortfall names the platform and count found instead of advising the
+    CPU flag."""
+    monkeypatch.setattr(ss, "_cpu_platform_in_use", lambda: cpu)
+    monkeypatch.setenv("XLA_FLAGS", "--xla_dump_to=/dev/null")
+    have = jax.device_count()
+    ss.ensure_host_devices(have)  # enough devices: returns quietly
+    with pytest.raises(RuntimeError) as err:
+        ss.ensure_host_devices(have + 1)
+    flags = os.environ["XLA_FLAGS"]
+    if cpu:
+        assert f"--xla_force_host_platform_device_count={have + 1}" in flags
+        assert "xla_force_host_platform_device_count" in str(err.value)
+    else:
+        assert flags == "--xla_dump_to=/dev/null"
+        assert f"found {have} {jax.default_backend()} device(s)" in str(err.value)
+        assert "xla_force_host_platform_device_count" not in str(err.value)
+
+
+@pytest.mark.parametrize("from_env", [True, False], ids=["env", "checkout"])
+def test_use_compile_cache_placement(monkeypatch, tmp_path, from_env):
+    """JAX_COMPILATION_CACHE_DIR wins and nothing else is set; without it
+    the cache sits at the checkout's fixed .jax_cache/."""
+    from repro.launch import use_compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    if from_env:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert use_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before
+        return
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    try:
+        assert use_compile_cache() == os.path.join(repo, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == os.path.join(repo, ".jax_cache")
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
