@@ -45,6 +45,7 @@ from repro.core import posterior, psvgp, svgp
 from repro.core.blend import predict_blended
 from repro.core.partition import PartitionGrid, make_grid, partition_data
 from repro.gp.covariances import CovarianceParams, make_covariance
+from repro.obs import span
 from repro.optim import AdamState, adam_init
 
 ARTIFACT_MANIFEST = "artifact.json"
@@ -156,10 +157,14 @@ class FittedPSVGP:
         self.state = state
         self._cache = cache
         # lifecycle observability: wall-clock of the training (or warm
-        # refit) that produced this state — None on loaded artifacts.
-        # Server.lifecycle() surfaces it per served version.
+        # refit) that produced this state — re-partitioning, topology
+        # build and SGD steps — and its split by phase ({partition_s,
+        # build_s, steps_s}); None on loaded artifacts.
+        # Server.lifecycle() surfaces the refit's per served version.
         self.train_seconds: float | None = None
+        self.train_phases: dict | None = None
         self.refit_seconds: float | None = None
+        self.refit_phases: dict | None = None
         # sharded-serving context (mesh, sharded cache, blend programs),
         # built and memoized by api.Server — kept here so several Server
         # views of one model (serial + pipelined lanes of a benchmark, say)
@@ -169,8 +174,9 @@ class FittedPSVGP:
     @property
     def cache(self) -> posterior.PosteriorCache:
         if self._cache is None:
-            self._cache = psvgp.posterior_cache(self.static, self.state)
-            jax.block_until_ready(self._cache)
+            with span("fit.cache"):
+                self._cache = psvgp.posterior_cache(self.static, self.state)
+                jax.block_until_ready(self._cache)
         return self._cache
 
     def predict(self, points) -> tuple[jnp.ndarray, jnp.ndarray]:
@@ -314,18 +320,29 @@ def _train(
     ``psvgp.init(PRNGKey(config.seed))`` state (``init_state=None`` — the
     ``fit()`` path) or the given warm state. One code path means the
     refit-from-scratch gate (refit == fit, bitwise) holds by construction.
+
+    ``train_seconds`` is the wall-clock of all three phases, and
+    ``train_phases`` its split, at the boundaries of the ``fit.partition``,
+    ``fit.build`` and ``fit.steps`` spans.
     """
-    grid = make_grid(x, config.grid, config.grid)
-    pdata = partition_data(x, y, grid)
+    t0 = time.perf_counter()
+    with span("fit.partition"):
+        grid = make_grid(x, config.grid, config.grid)
+        pdata = partition_data(x, y, grid)
+    t1 = time.perf_counter()
     pcfg = _psvgp_config(config)
-    static = psvgp.build(pcfg, pdata)
-    if init_state is None:
-        init_state = psvgp.init(jax.random.PRNGKey(config.seed), pcfg, pdata)
-    t0 = time.time()
-    state = psvgp.fit(static, init_state, pdata, config.train_iters)
-    jax.block_until_ready(state.params)
+    with span("fit.build"):
+        static = psvgp.build(pcfg, pdata)
+        if init_state is None:
+            init_state = psvgp.init(jax.random.PRNGKey(config.seed), pcfg, pdata)
+    t2 = time.perf_counter()
+    with span("fit.steps", steps=config.train_iters):
+        state = psvgp.fit(static, init_state, pdata, config.train_iters)
+        jax.block_until_ready(state.params)
+    t3 = time.perf_counter()
     fitted = FittedPSVGP(config, grid, static, state)
-    fitted.train_seconds = time.time() - t0
+    fitted.train_seconds = t3 - t0
+    fitted.train_phases = {"partition_s": t1 - t0, "build_s": t2 - t1, "steps_s": t3 - t2}
     return fitted
 
 
@@ -389,8 +406,10 @@ def refit(
         this is bitwise-identical to ``fit()`` on the new slice (gated
         in tests/test_lifecycle.py).
 
-    ``result.refit_seconds`` records the wall-clock of the step (the
-    lifecycle SLO input; ``save_step`` persists it into the store index).
+    ``result.refit_seconds`` records the wall-clock of the step:
+    re-partitioning, topology build and SGD steps, not the posterior
+    cache (the lifecycle SLO input; ``save_step`` persists it into the
+    store index). ``result.refit_phases`` splits it by phase.
     """
     cfg = RefitConfig() if config is None else config
     fit_cfg = fitted.config
@@ -409,6 +428,7 @@ def refit(
             )
     new = _train(fit_cfg, x, y, warm)
     new.refit_seconds = new.train_seconds
+    new.refit_phases = new.train_phases
     if verbose:
         print(
             f"refit ({cfg.init}) P={new.grid.num_partitions} partitions, "

@@ -61,9 +61,11 @@ setup BEFORE jax initializes).
 from __future__ import annotations
 
 import asyncio
+import collections
 import concurrent.futures
 import dataclasses
 import threading
+import time
 from typing import Any
 
 import numpy as np
@@ -71,8 +73,11 @@ import numpy as np
 from repro.api.config import FrontDoorConfig
 from repro.api.server import Server
 from repro.core import routing
+from repro.obs import Tally, span
 
 _SENTINEL = object()  # queue wake-up marker posted by close()
+# requests whose end-to-end latency report() keeps for its percentiles
+LATENCY_RING = 1 << 16
 
 
 class RequestRejected(RuntimeError):
@@ -110,6 +115,7 @@ class _Batch:
     reqs: list[_Request]
     sizes: np.ndarray  # (R,) rows per request, coalesce order
     handle: Any  # whatever the submit stage returned (pending device work)
+    index: int  # dispatch ordinal, the ``batch`` stat of its spans
 
 
 class FrontDoor:
@@ -144,8 +150,9 @@ class FrontDoor:
         self._dispatch_pool = concurrent.futures.ThreadPoolExecutor(
             max_workers=1, thread_name_prefix="frontdoor-dispatch"
         )
-        # guards the per-batch counters: written by the dispatch thread,
-        # read by report() on the event loop (see analysis RR006)
+        # guards the per-batch counters: written by the dispatch and
+        # collect threads, read by report() on the event loop (see
+        # analysis RR006)
         self._stats_lock = threading.Lock()
         self._closing = False
         self._broken: BaseException | None = None  # engine crash, if any
@@ -157,9 +164,16 @@ class FrontDoor:
         self._shed = 0
         self._delayed = 0
         self._recompiles = 0
-        self._latency_s: list[float] = []
-        self._batch_rows: list[int] = []
-        self._batch_requests: list[int] = []
+        self._latency_s: collections.deque = collections.deque(maxlen=LATENCY_RING)
+        self._batch_rows = Tally()
+        self._batch_requests = Tally()
+        self._batch_seq = 0  # dispatch thread only
+        # stage timings (report()["stages"]): queue_wait, dispatch and
+        # collect under _stats_lock; demux belongs to the event loop
+        self._queue_wait = Tally()
+        self._dispatch_time = Tally()
+        self._collect_time = Tally()
+        self._demux_time = Tally()
 
     # -- client side -------------------------------------------------------
 
@@ -282,7 +296,7 @@ class FrontDoor:
                         reqs = gathered
                 if reqs:
                     batch = await loop.run_in_executor(
-                        self._dispatch_pool, self._dispatch, reqs
+                        self._dispatch_pool, self._dispatch, reqs, loop.time
                     )
                     reqs = []  # futures now owned by the batch's resolve
                     if pending is not None:
@@ -350,22 +364,43 @@ class FrontDoor:
         pol = self.server.policy
         return int(pol.compiles) if pol is not None else 0
 
-    def _dispatch(self, reqs: list[_Request]) -> _Batch:
+    def _dispatch(self, reqs: list[_Request], clock) -> _Batch:
         """Coalesce + route + async-dispatch one window. Runs in the
         dispatch worker thread (the same work ``pipelined_request_loop``
         overlaps with the device) so a recompile never blocks the event
         loop; the per-batch counters it updates are read by ``report()``
-        on the loop thread, hence the lock."""
-        pts, sizes = routing.coalesce_requests([r.points for r in reqs])
-        before = self._policy_compiles()
-        handle = self._submit(self._route(pts))
-        grew = self._policy_compiles() - before
+        on the loop thread, hence the lock. ``clock`` is the event loop's
+        (``loop.time``), the clock of each request's ``t_arrival``."""
+        start = clock()
+        index = self._batch_seq
+        self._batch_seq += 1
+        rows = sum(r.n for r in reqs)
+        with span("frontdoor.dispatch", batch=index, rows=rows, requests=len(reqs)):
+            pts, sizes = routing.coalesce_requests([r.points for r in reqs])
+            before = self._policy_compiles()
+            handle = self._submit(self._route(pts))
+            grew = self._policy_compiles() - before
+        elapsed = clock() - start
         with self._stats_lock:
             if grew:  # this window burst the q_max high-water mark
                 self._recompiles += grew
-            self._batch_rows.append(int(sizes.sum()))
-            self._batch_requests.append(len(reqs))
-        return _Batch(reqs, sizes, handle)
+            self._batch_rows.add(rows)
+            self._batch_requests.add(len(reqs))
+            for r in reqs:
+                self._queue_wait.add(start - r.t_arrival)
+            self._dispatch_time.add(elapsed)
+        return _Batch(reqs, sizes, handle, index)
+
+    def _collect_batch(self, batch: _Batch) -> tuple[np.ndarray, np.ndarray]:
+        """The collect stage for one batch: block on its device results
+        and copy them to the host. Runs in the collect worker thread."""
+        t0 = time.perf_counter()
+        with span("frontdoor.collect", batch=batch.index):
+            out = self._collect(batch.handle)
+        elapsed = time.perf_counter() - t0
+        with self._stats_lock:
+            self._collect_time.add(elapsed)
+        return out
 
     async def _resolve(self, batch: _Batch) -> None:
         """Block on batch's device results (collect thread), demux, and
@@ -375,17 +410,20 @@ class FrontDoor:
         loop = asyncio.get_running_loop()
         try:
             mean, var = await loop.run_in_executor(
-                self._pool, self._collect, batch.handle
+                self._pool, self._collect_batch, batch
             )
-            outs = routing.demux_results(batch.sizes, mean, var)
+            t0 = time.perf_counter()
+            with span("frontdoor.demux", batch=batch.index):
+                outs = routing.demux_results(batch.sizes, mean, var)
+                now = loop.time()
+                for req, out in zip(batch.reqs, outs, strict=True):
+                    if not req.future.done():
+                        req.future.set_result(out)
+                    self._latency_s.append(now - req.t_arrival)
+            self._demux_time.add(time.perf_counter() - t0)
         except Exception as err:
             self._fail_requests(batch.reqs, err)
             return
-        now = loop.time()
-        for req, out in zip(batch.reqs, outs, strict=True):
-            if not req.future.done():
-                req.future.set_result(out)
-            self._latency_s.append(now - req.t_arrival)
         self._completed += len(batch.reqs)
 
     # -- SLO report --------------------------------------------------------
@@ -395,23 +433,44 @@ class FrontDoor:
 
         Fields: ``requests`` (arrived / admitted / completed / shed /
         delayed), ``batches`` (count, rows and requests per coalesced
-        batch), ``latency_ms`` (p50/p95/p99 END-TO-END per request:
-        admission to future resolution, queueing included — unlike the
-        per-batch service intervals of ``Server.stream``), ``recompiles``
-        (windows that burst the streaming q_max high-water mark — each
-        one recompiled the device program while the admission queue
-        absorbed, delayed, or shed the concurrent arrivals), plus the
-        policy stats, both configs, and the server's ``lifecycle``
-        section (``Server.lifecycle``: swaps, active version, requests
-        served and refit wall-clock per model version — the front door
-        keeps admitting straight through a ``Server.swap``, and this is
-        where that shows up).
+        batch), ``latency_ms`` (p50/p95/p99 END-TO-END per request over
+        the last ``LATENCY_RING`` completed: admission to future
+        resolution, queueing included — unlike the per-batch service
+        intervals of ``Server.stream``), ``stages`` (count / mean_ms /
+        max_ms of each stage: ``queue_wait`` per request, admission to
+        the start of its batch's dispatch — the queue plus the batching
+        window; ``dispatch`` (coalesce, route, submit), ``collect``
+        (device wait and device-to-host copy) and ``demux`` (split and
+        settle the futures) per batch, at the boundaries of the
+        ``frontdoor.*`` spans), ``recompiles`` (windows that burst the
+        streaming q_max high-water mark — each one recompiled the device
+        program while the admission queue absorbed, delayed, or shed the
+        concurrent arrivals), plus the policy stats, both configs, and
+        the server's ``lifecycle`` section (``Server.lifecycle``: swaps,
+        active version, requests served and refit wall-clock per model
+        version — the front door keeps admitting straight through a
+        ``Server.swap``, and this is where that shows up).
+
+        Every counter is a running sum: the report's size does not grow
+        with the traffic served.
         """
         with self._stats_lock:
-            rows = np.asarray(self._batch_rows, np.int64)
-            per = np.asarray(self._batch_requests, np.int64)
+            rows, per = self._batch_rows, self._batch_requests
+            batches = {
+                "count": rows.count,
+                "rows_total": int(rows.total),
+                "rows_per_batch_mean": float(rows.mean),
+                "rows_per_batch_max": int(rows.max),
+                "requests_per_batch_mean": float(per.mean),
+            }
+            stages = {
+                "queue_wait": self._queue_wait.ms(),
+                "dispatch": self._dispatch_time.ms(),
+                "collect": self._collect_time.ms(),
+            }
             recompiles = self._recompiles
-        lat = np.sort(np.asarray(self._latency_s, np.float64)) * 1e3
+        stages["demux"] = self._demux_time.ms()
+        lat = np.asarray(self._latency_s, np.float64) * 1e3
         pct = (
             {
                 "p50_ms": float(np.percentile(lat, 50)),
@@ -432,14 +491,9 @@ class FrontDoor:
                 "shed": self._shed,
                 "delayed": self._delayed,
             },
-            "batches": {
-                "count": int(rows.size),
-                "rows_total": int(rows.sum()) if rows.size else 0,
-                "rows_per_batch_mean": float(rows.mean()) if rows.size else 0.0,
-                "rows_per_batch_max": int(rows.max()) if rows.size else 0,
-                "requests_per_batch_mean": float(per.mean()) if per.size else 0.0,
-            },
+            "batches": batches,
             "latency_ms": pct,
+            "stages": stages,
             "recompiles": recompiles,
             "qmax_policy": pol.stats() if pol is not None else None,
             "lifecycle": self.server.lifecycle(),

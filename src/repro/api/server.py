@@ -58,6 +58,7 @@ import numpy as np
 
 from repro.api.config import ServeConfig
 from repro.api.fitted import FittedPSVGP
+from repro.obs import span
 
 
 class _ServingContext:
@@ -203,8 +204,10 @@ class Server:
             return fitted.predict(pts)
 
         def collect(pending):
-            jax.block_until_ready(pending)
-            return np.asarray(pending[0]), np.asarray(pending[1])
+            with span("server.wait"):
+                jax.block_until_ready(pending)
+            with span("server.fetch"):
+                return np.asarray(pending[0]), np.asarray(pending[1])
 
         ctx.route, ctx.submit, ctx.collect = route, submit, collect
 
@@ -269,14 +272,18 @@ class Server:
     def lifecycle(self) -> dict:
         """The lifecycle section of the SLO report: swap count, the active
         version, and per-version history — requests served, refit
-        wall-clock (``FittedPSVGP.refit_seconds``), and context build
-        time (the double-buffered work a swap did off the request path).
+        wall-clock (``FittedPSVGP.refit_seconds``: re-partitioning,
+        topology build and SGD steps) and its split by phase
+        (``refit_phases``: ``partition_s``, ``build_s``, ``steps_s``), and
+        context build time (``build_s``: the double-buffered work a swap
+        did off the request path).
         """
         versions = [
             {
                 "version": c.version,
                 "requests": c.requests,
                 "refit_s": c.fitted.refit_seconds,
+                "refit_phases": c.fitted.refit_phases,
                 "build_s": c.build_seconds,
             }
             for c in (*self._retired, self._active)

@@ -37,6 +37,7 @@ import numpy as np
 from repro.core import posterior
 from repro.core.partition import PartitionGrid
 from repro.core.psvgp import PSVGPState, PSVGPStatic, posterior_cache
+from repro.obs import span
 
 
 def corner_ids_weights(grid: PartitionGrid, pts: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
@@ -147,7 +148,11 @@ def predict_blended(
     factors partitioned over a device mesh) is
     ``repro.launch.serve_sharded``."""
     pts = np.asarray(points, np.float32)
-    ids, w = corner_ids_weights(grid, pts)
+    with span("blend.corners"):
+        ids, w = corner_ids_weights(grid, pts)
     if cache is None:
         cache = posterior_cache(static, state)
-    return _blend_eval(cache, static.cov_fn, jnp.asarray(pts), jnp.asarray(ids), jnp.asarray(w))
+    with span("blend.transfer"):
+        args = jnp.asarray(pts), jnp.asarray(ids), jnp.asarray(w)
+    with span("blend.launch"):
+        return _blend_eval(cache, static.cov_fn, *args)
