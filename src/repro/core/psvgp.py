@@ -35,6 +35,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core import posterior, svgp
 from repro.core.neighbors import direction_permutations, neighbor_table
@@ -206,26 +207,49 @@ def train_step_ppermute(
     return PSVGPState(new_params, new_opt, state.step + 1), jnp.mean(losses)
 
 
+def _configured_step(state, key, x, y, mask, dist, perms, p_dir, cfg, cov_fn):
+    """The step ``cfg.comm`` names, looked up as a module global at each
+    call (inside ``fit_steps``, when its loop body is traced)."""
+    if cfg.comm == "gather":
+        return train_step_gather(state, key, x, y, mask, dist, cfg, cov_fn)
+    if cfg.comm == "ppermute":
+        return train_step_ppermute(state, key, x, y, mask, dist, perms, p_dir, cfg, cov_fn)
+    raise ValueError(f"unknown comm mode {cfg.comm!r}")
+
+
 def train_step(static: PSVGPStatic, state: PSVGPState, key: jax.Array, data: PartitionedData):
-    """Dispatch on the configured communication mode."""
-    if static.cfg.comm == "gather":
-        return train_step_gather(
-            state, key, data.x, data.y, data.mask, static.dist, static.cfg, static.cov_fn
-        )
-    elif static.cfg.comm == "ppermute":
-        return train_step_ppermute(
-            state,
-            key,
-            data.x,
-            data.y,
-            data.mask,
-            static.dist,
-            static.perms,
-            static.p_dir,
-            static.cfg,
-            static.cov_fn,
-        )
-    raise ValueError(f"unknown comm mode {static.cfg.comm!r}")
+    """One step, dispatched from the host, of the configured communication
+    mode (``fit`` runs its steps in one device program instead)."""
+    return _configured_step(state, key, data.x, data.y, data.mask, static.dist, static.perms,
+                            static.p_dir, static.cfg, static.cov_fn)
+
+
+@functools.partial(jax.jit, static_argnames=("cfg", "cov_fn"))
+def fit_steps(
+    state: PSVGPState,
+    num_iters: jnp.ndarray,
+    key: jax.Array,
+    x: jnp.ndarray,
+    y: jnp.ndarray,
+    mask: jnp.ndarray,
+    dist: SlotDistribution,
+    perms: jnp.ndarray,
+    p_dir: jnp.ndarray,
+    cfg: PSVGPConfig,
+    cov_fn: Callable,
+) -> tuple[PSVGPState, jnp.ndarray]:
+    """``num_iters`` steps of the configured step in one device program.
+
+    The trip count is traced, so every step budget over the same shapes
+    shares one compiled program. The data and topology tables are loop
+    invariants; only ``(state, last loss)`` is carried. Returns the loss
+    of the last step run, NaN when none ran.
+    """
+
+    def body(_, carry):
+        return _configured_step(carry[0], key, x, y, mask, dist, perms, p_dir, cfg, cov_fn)
+
+    return jax.lax.fori_loop(0, num_iters, body, (state, jnp.full((), jnp.nan, jnp.float32)))
 
 
 def fit(
@@ -235,55 +259,30 @@ def fit(
     num_iters: int,
     key: jax.Array | None = None,
     log_every: int = 0,
-    use_scan: bool = False,
 ) -> PSVGPState:
     """Run ``num_iters`` SGD iterations (the paper runs 100-150 per E3SM
-    time step budget; convergence experiments run a few thousand).
+    time step; convergence experiments run a few thousand).
 
-    use_scan batches iterations inside one XLA program via lax.scan.
-    §Perf-3 log: HYPOTHESIS REFUTED on CPU — the scan carry double-buffers
-    the whole (params, opt) state per iteration and measured 2.5x SLOWER
-    than the python loop (7.4 -> 18.5 ms/iter at P=100, m=5), so the
-    default stays False; kept as an option since on TPU with donated
-    buffers the trade-off may invert. Identical math either way (keys are
-    fold_in(key, step)).
+    The steps run on the device inside ``fit_steps``, one dispatch for the
+    whole budget; with ``log_every`` the budget runs in chunks of that many
+    steps through the same program, printing the mean loss after each.
+    Step ``i`` draws from ``fold_in(key, state.step)``, so a state carried
+    over from an earlier call continues the key sequence, and the chunking
+    does not change the result. The input state is not donated: it is
+    never modified.
     """
     key = jax.random.PRNGKey(static.cfg.seed) if key is None else key
-    if use_scan and not log_every:
-        chunk = min(num_iters, 200)  # bound one program's trace length
-
-        if static.cfg.comm == "gather":
-            args = (data.x, data.y, data.mask, static.dist, static.cfg, static.cov_fn)
-            step_fn = train_step_gather
-        else:
-            args = (data.x, data.y, data.mask, static.dist, static.perms,
-                    static.p_dir, static.cfg, static.cov_fn)
-            step_fn = train_step_ppermute
-
-        import functools as _ft
-
-        @_ft.partial(jax.jit, static_argnames=())
-        def run_chunk(st):
-            def body(s, _):
-                s2, loss = step_fn(s, key, *args)
-                return s2, loss
-
-            return jax.lax.scan(body, st, None, length=chunk)
-
-        done = 0
-        while done < num_iters:
-            n = min(chunk, num_iters - done)
-            if n == chunk:
-                state, _ = run_chunk(state)
-            else:
-                for _ in range(n):
-                    state, _ = train_step(static, state, key, data)
-            done += n
-        return state
-    for i in range(num_iters):
-        state, loss = train_step(static, state, key, data)
-        if log_every and (i + 1) % log_every == 0:
-            print(f"  iter {i + 1:5d}  mean -ELBO/partition: {float(loss):.4f}")
+    chunk = log_every or num_iters
+    done = 0
+    while done < num_iters:
+        n = min(chunk, num_iters - done)
+        state, loss = fit_steps(
+            state, np.int32(n), key, data.x, data.y, data.mask, static.dist,
+            static.perms, static.p_dir, static.cfg, static.cov_fn,
+        )
+        done += n
+        if log_every:
+            print(f"  iter {done:5d}  mean -ELBO/partition: {float(loss):.4f}")
     return state
 
 

@@ -97,17 +97,13 @@ def gather_minibatch(
     mask: jnp.ndarray,
     kprime: jnp.ndarray,
     idx: jnp.ndarray,
-    bmask_from_source: jnp.ndarray | None = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Materialize the (P, B, ...) mini-batches from source partitions kprime.
 
-    This is the paper-faithful "gather" communication mode: under SPMD the
-    cross-partition take lowers to a gather/all-gather of B-point blocks.
+    This is the paper-faithful "gather" communication mode: partition j
+    reads the B points ``idx[j]`` of partition ``kprime[j]``, and nothing
+    else of it; under SPMD the cross-partition read lowers to a gather of
+    B-point blocks.
     """
-    xs = jnp.take(x, kprime, axis=0)  # (P, n_max, d)
-    ys = jnp.take(y, kprime, axis=0)
-    ms = jnp.take(mask, kprime, axis=0)
-    bx = jnp.take_along_axis(xs, idx[:, :, None], axis=1)  # (P, B, d)
-    by = jnp.take_along_axis(ys, idx, axis=1)  # (P, B)
-    bm = jnp.take_along_axis(ms, idx, axis=1)
-    return bx, by, bm
+    rows = kprime[:, None]  # (P, 1) against idx (P, B)
+    return x[rows, idx], y[rows, idx], mask[rows, idx]

@@ -126,3 +126,99 @@ def test_no_nans_with_tiny_partitions():
     static, state = _train(data, delta=0.5, comm="gather", iters=100, m=4, B=8)
     assert all(np.isfinite(np.asarray(l)).all() for l in jax.tree.leaves(state.params))
     assert np.isfinite(float(rmspe(static, state, data)))
+
+
+# --------------------------------------------------------------------------
+# psvgp.fit runs its steps in one device program (fit_steps)
+# --------------------------------------------------------------------------
+
+
+@pytest.fixture(scope="module", params=["gather", "ppermute"])
+def fit_case(request, small_problem):
+    _, _, data, _ = small_problem
+    cfg = psvgp.PSVGPConfig(
+        svgp=svgp.SVGPConfig(num_inducing=8, input_dim=2),
+        delta=0.15, batch_size=16, learning_rate=0.05, comm=request.param,
+    )
+    static = psvgp.build(cfg, data)
+    return static, psvgp.init(jax.random.PRNGKey(0), cfg, data), data
+
+
+def _stepwise(static, state, data, n):
+    key = jax.random.PRNGKey(static.cfg.seed)
+    for _ in range(n):
+        state, _ = psvgp.train_step(static, state, key, data)
+    return state
+
+
+def _leaves_equal(a, b):
+    return all(np.array_equal(np.asarray(u), np.asarray(v))
+               for u, v in zip(jax.tree.leaves(a), jax.tree.leaves(b), strict=True))
+
+
+def _assert_states_close(a, b):
+    # XLA may fuse the loop body differently from the standalone step and
+    # reassociate the ELBO's reductions: a few float32 ulps (~1e-7
+    # relative) per step, which five Adam steps keep far under 1e-5 of a
+    # leaf's scale. On the CPU the two are bitwise equal.
+    assert int(a.step) == int(b.step)
+    for u, v in zip(jax.tree.leaves(a), jax.tree.leaves(b), strict=True):
+        np.testing.assert_allclose(np.asarray(u), np.asarray(v), rtol=1e-5, atol=1e-6)
+
+
+def test_fit_matches_stepwise_train_step(fit_case):
+    """fit(n) is n calls of train_step, in one device program."""
+    static, state0, data = fit_case
+    _assert_states_close(psvgp.fit(static, state0, data, 5), _stepwise(static, state0, data, 5))
+
+
+def test_fit_continues_the_key_sequence(fit_case):
+    """From a state at step k, fit draws the keys of steps k..k+n-1: it
+    equals train_step from that state, and differs from a run whose step
+    counter is reset to 0 (which would replay steps 0..n-1's batches)."""
+    static, state0, data = fit_case
+    k, n = 3, 4
+    warm = psvgp.fit(static, state0, data, k)
+    got = psvgp.fit(static, warm, data, n)
+    assert int(got.step) == k + n
+    _assert_states_close(got, _stepwise(static, warm, data, n))
+    replayed = psvgp.fit(static, warm._replace(step=jnp.zeros((), jnp.int32)), data, n)
+    assert not _leaves_equal(got.params, replayed.params)
+
+
+def test_fit_zero_steps_returns_state_unchanged(fit_case):
+    static, state0, data = fit_case
+    assert _leaves_equal(psvgp.fit(static, state0, data, 0), state0)
+
+
+def test_fit_log_every_gives_the_same_state(fit_case, capsys):
+    """Chunks of log_every steps run the same program on the carried
+    state, so the result is bitwise the unchunked one; one line is
+    printed per chunk, the last a partial one."""
+    static, state0, data = fit_case
+    logged = psvgp.fit(static, state0, data, 7, log_every=3)
+    assert _leaves_equal(logged, psvgp.fit(static, state0, data, 7))
+    lines = capsys.readouterr().out.splitlines()
+    assert [line.split()[1] for line in lines] == ["3", "6", "7"]
+    assert all(np.isfinite(float(line.split()[-1])) for line in lines)
+
+
+def test_fit_new_step_budget_compiles_nothing(fit_case):
+    """The trip count is traced: a different num_iters over the same
+    shapes reuses the compiled program."""
+    static, state0, data = fit_case
+    jax.block_until_ready(psvgp.fit(static, state0, data, 2))
+    compiles = []
+
+    def listen(event, duration, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(duration)
+
+    before = psvgp.fit_steps._cache_size()
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        jax.block_until_ready(psvgp.fit(static, state0, data, 11))
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listen)
+    assert compiles == []
+    assert psvgp.fit_steps._cache_size() == before

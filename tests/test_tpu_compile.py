@@ -118,3 +118,75 @@ def test_sharded_fused_blend_compiles_for_v5e_2x2(topo, monkeypatch):
     assert 4 <= _op_count(text, "collective-permute") <= 8
     for gathering in ("all-gather", "all-reduce", "all-to-all"):
         assert _op_count(text, gathering) == 0, gathering
+
+
+def _computations(hlo_text: str) -> dict:
+    """Compiled HLO text -> {computation name: its instruction lines}."""
+    comps, body = {}, None
+    for line in hlo_text.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) .*\{$", line)
+        if head:
+            body = comps[head.group(1)] = []
+        elif body is not None:
+            body.append(line)
+    return comps
+
+
+def _called(comps: dict, root: str) -> set:
+    """``root`` and every computation it calls, transitively."""
+    seen, todo = set(), [root]
+    while todo:
+        name = todo.pop()
+        if name in seen or name not in comps:
+            continue
+        seen.add(name)
+        for line in comps[name]:
+            for ref in re.findall(r"(?:calls|body|condition|to_apply|branch_computations)=(\{[^}]*\}|%[\w.\-]+)", line):
+                todo += re.findall(r"%([\w.\-]+)", ref)
+    return seen
+
+
+# instructions that hand an array on without making a new one
+_PASS_THROUGH = {"parameter", "get-tuple-element", "tuple", "bitcast"}
+
+
+def test_fit_loop_compiles_for_v5e(one_chip):
+    """``psvgp.fit_steps`` at the E3SM shapes (400 partitions, the fullest
+    of the 48,602 points' holding 248, m = 5, B = 32, comm="gather") is one
+    device loop, and no step copies the data: inside the loop body nothing
+    the size of x (P x n_max x 2) is made, only passed through."""
+    from repro.core import psvgp, svgp
+    from repro.core.partition import PartitionedData, make_grid
+
+    P, n_max = 400, 248
+    grid = make_grid(np.array([[0.0, 0.0], [1.0, 1.0]], np.float32), 20, 20)
+    data = PartitionedData(
+        x=jnp.zeros((P, n_max, D)), y=jnp.zeros((P, n_max)), mask=jnp.ones((P, n_max)),
+        counts=jnp.full((P,), 122, jnp.int32), grid=grid,
+    )
+    cfg = psvgp.PSVGPConfig(
+        svgp=svgp.SVGPConfig(num_inducing=5, input_dim=D), delta=0.125, batch_size=32,
+        learning_rate=0.05, comm="gather",
+    )
+    static = psvgp.build(cfg, data)
+    state = jax.eval_shape(lambda k: psvgp.init(k, cfg, data), jax.random.PRNGKey(0))
+    args = jax.tree.map(
+        lambda a: _sds(one_chip, *a.shape, dtype=a.dtype),
+        (state, jnp.int32(150), jax.random.PRNGKey(0), data.x, data.y, data.mask,
+         static.dist, static.perms, static.p_dir),
+    )
+    text = psvgp.fit_steps.lower(*args, cfg=cfg, cov_fn=static.cov_fn).compile().as_text()
+    assert _op_count(text, "while") == 1
+    comps = _computations(text)
+    (loop,) = [line for lines in comps.values() for line in lines if re.search(r"\bwhile\(", line)]
+    body = re.search(r"body=%([\w.\-]+)", loop).group(1)
+    x_shape = f"f32[{P},{n_max},{D}]"
+    ops = [
+        (inst.group(2), inst.group(1), line.strip()[:160])
+        for name in _called(comps, body)
+        for line in comps[name]
+        if (inst := re.match(r"\s*(?:ROOT )?%\S+ = (.+?) ([a-z][\w\-]*)\(", line))
+    ]
+    assert ("get-tuple-element", x_shape) in {(op, t.split("{")[0]) for op, t, _ in ops}
+    made = [line for op, t, line in ops if x_shape in t and op not in _PASS_THROUGH]
+    assert not made, made
